@@ -16,6 +16,8 @@ import json
 import os
 import time
 
+from repro.launch.compile_cache import REPO_ROOT, enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -23,6 +25,7 @@ def main() -> None:
                     help="include the runtime serving benchmarks "
                          "(serve_throughput, chaos_resilience)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (coded_overhead, fig2_data_loss, fig12_recovery,
                             fig16_straggler, fig17_coverage, multi_failure,
@@ -61,8 +64,9 @@ def main() -> None:
                        if not str(k).startswith("us_")}
             print(f"{name},{us_val},\"{derived}\"")
 
-    os.makedirs("/root/repo/results", exist_ok=True)
-    with open("/root/repo/results/bench.json", "w") as f:
+    out_dir = os.path.join(REPO_ROOT, "results")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "bench.json"), "w") as f:
         json.dump(all_results, f, indent=1, default=str)
     print(f"# wrote results/bench.json with {len(all_results)} suites")
 

@@ -165,9 +165,11 @@ def unfold_parity(p_slots: jax.Array, T: int, r: int) -> jax.Array:
 
 def _shardwise_matmul(x: jax.Array, w_stacked: jax.Array,
                       dtype=None) -> jax.Array:
-    """y[d] = x @ w_stacked[d];  x: [..., k], w: [T, k, c] -> [T, ..., c]."""
+    """y[d] = x @ w_stacked[d];  x: [..., k], w: [T, k, c] -> [T, ..., c].
+    Full precision, so f32 parity weights stay f32 on a TPU."""
     return jnp.einsum("...k,dkc->d...c", x, w_stacked,
-                      preferred_element_type=dtype or x.dtype)
+                      preferred_element_type=dtype or x.dtype,
+                      precision=coding.EXACT)
 
 
 def merge_shards(ys: jax.Array) -> jax.Array:
@@ -270,14 +272,18 @@ def coded_matmul(
     k, m = w.shape
     m_l = m // T
     w_st = jnp.moveaxis(w.reshape(k, T, m_l), 1, 0)  # [T, k, m_l]
-    ys = _shardwise_matmul(x, w_st)  # [T, ..., m_l]
-
     if w_cdc is None or code.n_parity == 0 or valid is None:
-        return merge_shards(ys)  # uncoded (or nothing to recover)
+        return merge_shards(_shardwise_matmul(x, w_st))  # nothing to recover
 
-    parity = _shardwise_matmul(x, w_cdc)  # dedicated [r,...,m_l] | slots
-    return decode_and_merge(ys, parity, spec, valid,
-                            valid_parity=valid_parity, acc_dtype=acc_dtype)
+    # shard and parity outputs stay in acc_dtype through the recovery and
+    # are rounded to the activation dtype once, after the merge: parity
+    # minus the surviving shards cancels, and a bf16 round before it
+    # would cost the recovered shard several ulps
+    ys = _shardwise_matmul(x, w_st, acc_dtype)           # [T, ..., m_l]
+    parity = _shardwise_matmul(x, w_cdc, acc_dtype)  # dedicated | slots
+    out = decode_and_merge(ys, parity, spec, valid,
+                           valid_parity=valid_parity, acc_dtype=acc_dtype)
+    return out.astype(x.dtype)
 
 
 def decode_folded(ys: jax.Array, p_slots: jax.Array, valid: jax.Array,
@@ -309,7 +315,8 @@ def decode_folded(ys: jax.Array, p_slots: jax.Array, valid: jax.Array,
 
     # residual_j = parity_j - sum_{i valid} gen[j,i] y_i  (valid y already
     # zeroed-out for dead i, so plain tensordot works)
-    residual = parity - jnp.tensordot(gen, y, axes=[[1], [0]])  # [r, ..., m_l]
+    residual = parity - jnp.tensordot(gen, y, axes=[[1], [0]],
+                                      precision=coding.EXACT)  # [r, ..., m_l]
 
     smap = jnp.asarray(folded_slot_map(T, r))  # [r, T(slices)]
     pv = valid_parity[smap]  # [r, T] parity validity per slice

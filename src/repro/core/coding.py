@@ -37,8 +37,10 @@ class CodeSpec:
     Attributes:
       n_shards: T, number of data shards (devices doing real output splits).
       n_parity: r, number of parity shards. r=1 => the paper's sum code.
-      parity_dtype: accumulation dtype for parity math (fp32 recommended when
-        shard outputs are bf16; see DESIGN.md §8).
+      parity_dtype: dtype of the parity math and of the stored parity
+        weights (fp32 recommended when weights and shard outputs are bf16:
+        a bf16 parity weight rounds sum_i gen[j, i] W_i, and Eq. 12 then
+        rebuilds a lost shard a few bf16 ulps off; see DESIGN.md §8).
     """
 
     n_shards: int
@@ -59,6 +61,12 @@ class CodeSpec:
     @functools.cached_property
     def generator(self) -> np.ndarray:
         return generator_matrix(self.n_shards, self.n_parity)
+
+
+#: Precision of the code's own f32 products (encode, residuals): a TPU's
+#: default f32 dot is one bf16 pass, which would round the generator
+#: coefficients and break exact Eq. 12 recovery.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def generator_matrix(n_shards: int, n_parity: int) -> np.ndarray:
@@ -116,14 +124,15 @@ def encode_weights(w_shards: jax.Array, spec: CodeSpec) -> jax.Array:
       spec: code spec with spec.n_shards == T.
 
     Returns:
-      [r, ..., m_shard] parity weights W_cdc[j] = sum_i gen[j, i] * W_i.
+      [r, ..., m_shard] parity weights W_cdc[j] = sum_i gen[j, i] * W_i, in
+      ``spec.parity_dtype`` (the shards' dtype when that is None).
     """
     if w_shards.shape[0] != spec.n_shards:
         raise ValueError(
             f"w_shards leading dim {w_shards.shape[0]} != T={spec.n_shards}")
     gen = jnp.asarray(spec.generator, dtype=spec.parity_dtype or w_shards.dtype)
-    acc = jnp.tensordot(gen, w_shards.astype(gen.dtype), axes=[[1], [0]])
-    return acc.astype(w_shards.dtype)
+    return jnp.tensordot(gen, w_shards.astype(gen.dtype), axes=[[1], [0]],
+                         precision=EXACT)
 
 
 def encode_outputs(y_shards: jax.Array, spec: CodeSpec) -> jax.Array:
@@ -132,7 +141,8 @@ def encode_outputs(y_shards: jax.Array, spec: CodeSpec) -> jax.Array:
     all shard outputs -- that is the whole point of the code)."""
     dtype = spec.parity_dtype or y_shards.dtype
     gen = jnp.asarray(spec.generator, dtype=dtype)
-    return jnp.tensordot(gen, y_shards.astype(dtype), axes=[[1], [0]])
+    return jnp.tensordot(gen, y_shards.astype(dtype), axes=[[1], [0]],
+                         precision=EXACT)
 
 
 def decode_outputs(
@@ -172,7 +182,8 @@ def decode_outputs(
 
     # MDS path: solve an r x r system for up to r erased shards.
     # residual_j = parity_j - sum_{i valid} gen[j,i] y_i = sum_{i missing} gen[j,i] y_i
-    residual = parity.astype(dtype) - jnp.tensordot(gen, y, axes=[[1], [0]])
+    residual = parity.astype(dtype) - jnp.tensordot(gen, y, axes=[[1], [0]],
+                                                    precision=EXACT)
     # Static-shape selection of (up to) r missing indices; slots beyond the
     # actual erasure count are padded with valid indices whose equations are
     # replaced by identity rows (harmless).

@@ -5,7 +5,6 @@
                parity decode crosses the `model` axis (all_gather + local
                subtract — the paper's master/worker message flow)
   pipeline     pipeline_apply: GPipe microbatching over the `pod` axis
-  compat       shard_map shim across jax API generations
 """
 from repro.dist.collectives import coded_matmul_shardmap
 from repro.dist.pipeline import pipeline_apply

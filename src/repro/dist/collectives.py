@@ -23,7 +23,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.coded_layer import (CodedDenseSpec, decode_and_merge,
                                     merge_shards)
-from repro.dist.compat import shard_map
 from repro.dist.sharding import batch_axes
 
 __all__ = ["coded_matmul_shardmap"]
@@ -100,9 +99,11 @@ def coded_matmul_shardmap(
                                                              None))
         args += [w_cdc, valid, valid_parity]
         in_specs += [P(None), P(None)]
-        fn = shard_map(local, mesh, tuple(in_specs), x_spec)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=x_spec, check_vma=False)
         return fn(*args)
 
-    fn = shard_map(lambda xb, wb: local(xb, wb, None, None, None), mesh,
-                   tuple(in_specs), x_spec)
+    fn = jax.shard_map(lambda xb, wb: local(xb, wb, None, None, None),
+                       mesh=mesh, in_specs=tuple(in_specs), out_specs=x_spec,
+                       check_vma=False)
     return fn(x, w_blocked)

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
-
 __all__ = ["pipeline_apply"]
 
 
@@ -88,6 +86,7 @@ def pipeline_apply(layer, params, x, *, mesh, n_microbatches: int = 4,
         # results live on the last stage; zero elsewhere + psum = broadcast
         return jax.lax.psum(jnp.where(stage == S - 1, out, 0), axis)
 
-    fn = shard_map(stage_fn, mesh, (p_spec, x_spec), x_spec)
+    fn = jax.shard_map(stage_fn, mesh=mesh, in_specs=(p_spec, x_spec),
+                       out_specs=x_spec, check_vma=False)
     y_mb = fn(p_blocked, x_mb)
     return y_mb.reshape((B,) + y_mb.shape[2:])

@@ -17,7 +17,9 @@ LM-head GEMM + Eq. 12 parity decode + greedy argmax in ONE kernel. Per
 column tile it computes every shard's head output y_d = x @ W_d plus the
 sum-parity output p = x @ W_cdc0, recovers an erased shard in-register, and
 folds a running (max, argmax) over the merged vocabulary — the [B, vocab]
-logits tensor is never materialised in HBM.
+logits tensor is never materialised in HBM. Grid (vocab tiles, k tiles),
+both sequential: the k axis accumulates (T+1) [b, bn] f32 tiles in VMEM,
+the last k step decodes and updates the running argmax.
 
 Erasure limit (ASYMMETRY with the reference path, by design): both kernels
 here consume exactly ONE parity equation — the all-ones sum row (paper
@@ -38,6 +40,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.cdc_matmul import LANE, col_block, k_block, mxu_dot
 
 
 def _decode_kernel(valid_ref, y_ref, p_ref, o_ref):
@@ -77,67 +82,77 @@ def cdc_decode_pallas(y_shards: jax.Array, parity: jax.Array,
 # ------------------------------------------------------ fused head+argmax ----
 
 NEG_INF = -1e30  # python float: jnp scalars would be captured consts
+_NO_ID = 2 ** 31 - 1   # id that loses every min()
 
 
 def _fused_head_kernel(valid_ref, x_ref, w_ref, pw_ref, oval_ref, oidx_ref,
-                       *, m_l: int, bn: int, vocab: int):
-    """One vocab tile of the fused coded head: GEMM -> Eq. 12 -> running
-    argmax. The grid walks the shard-local column tiles sequentially; the
-    (b, 1) output blocks are revisited at every step and carry the running
-    (max logit, global argmax) across tiles."""
-    j = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)            # [b, k]
-    w = w_ref[...].astype(jnp.float32)            # [T, k, bn]
-    pw = pw_ref[...].astype(jnp.float32)          # [k, bn]
-    valid = valid_ref[...]                        # [T] bool
-    T = w.shape[0]
+                       acc_ref, *, m_l: int, bn: int, vocab: int):
+    """One (vocab tile j, contraction tile kk) step of the fused coded
+    head: accumulate the T shard GEMMs and the sum-parity GEMM over k,
+    then at the last k step Eq. 12-decode and fold the tile into the
+    running argmax. The (b, 1) output blocks are revisited at every step
+    and carry the running (max logit, global argmax) across tiles."""
+    j, kk = pl.program_id(0), pl.program_id(1)
+    T = w_ref.shape[0]
+
+    @pl.when(kk == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     # coded matmul: every shard's tile plus the sum-parity tile (MXU)
-    y = jnp.einsum("bk,tkn->tbn", x, w,
-                   preferred_element_type=jnp.float32)
-    p = jnp.dot(x, pw, preferred_element_type=jnp.float32)   # [b, bn]
+    x = x_ref[...]                                 # [b, bk]
+    for t in range(T):
+        acc_ref[t] += mxu_dot(x, w_ref[t])
+    acc_ref[T] += mxu_dot(x, pw_ref[...])
 
-    # parity decode (Eq. 12): zero the erased shard, rebuild it from parity
-    vm = valid.astype(jnp.float32)[:, None, None]
-    yz = y * vm
-    missing = p - jnp.sum(yz, axis=0)             # [b, bn]
-    rec = yz + (1.0 - vm) * missing[None]         # [T, b, bn]
-
-    # merged-vocab column ids: shard t's tile covers t*m_l + j*bn + c
-    t_ids = jax.lax.broadcasted_iota(jnp.int32, (T, bn), 0)
-    c_ids = jax.lax.broadcasted_iota(jnp.int32, (T, bn), 1)
-    gid = t_ids * m_l + j * bn + c_ids            # [T, bn]
-
-    logits = jnp.moveaxis(rec, 1, 0)              # [b, T, bn]
-    logits = jnp.where((gid < vocab)[None], logits, NEG_INF)
-    flat = logits.reshape(logits.shape[0], T * bn)
-    # gid is strictly increasing along the flat (t-major) order, so the
-    # first-occurrence argmax below is also the smallest global id
-    vmax = jnp.max(flat, axis=1)                  # [b]
-    amax = jnp.argmax(flat, axis=1).astype(jnp.int32)
-    gbest = (amax // bn) * m_l + j * bn + amax % bn
-
-    nv, ni = vmax[:, None], gbest[:, None]
-
-    @pl.when(j == 0)
+    @pl.when(kk == pl.num_programs(1) - 1)
     def _():
-        oval_ref[...] = nv
-        oidx_ref[...] = ni
+        # parity decode (Eq. 12): zero the erased shard, rebuild it from
+        # the sum parity
+        alive = [valid_ref[t] != 0 for t in range(T)]
+        yz = [jnp.where(alive[t], acc_ref[t], 0.0) for t in range(T)]
+        missing = acc_ref[T]
+        for t in range(T):
+            missing = missing - yz[t]
+        # shard t's tile covers merged-vocab ids t*m_l + j*bn + c; columns
+        # past m_l (the overhanging last tile) or the vocab never win
+        col = j * bn + jax.lax.broadcasted_iota(jnp.int32, missing.shape, 1)
+        nv = ni = None
+        for t in range(T):
+            gid = t * m_l + col
+            logit = jnp.where((col < m_l) & (gid < vocab),
+                              jnp.where(alive[t], yz[t], missing), NEG_INF)
+            v = jnp.max(logit, axis=1, keepdims=True)          # [b, 1]
+            i = jnp.min(jnp.where(logit == v, gid, _NO_ID), axis=1,
+                        keepdims=True)
+            if nv is None:
+                nv, ni = v, i
+            else:
+                # ids grow with t inside a tile: ties keep the earlier one
+                better = v > nv
+                nv, ni = jnp.where(better, v, nv), jnp.where(better, i, ni)
 
-    @pl.when(j > 0)
-    def _():
-        cv, ci = oval_ref[...], oidx_ref[...]
-        # strict argmax semantics: ties go to the smaller global id
-        better = (nv > cv) | ((nv == cv) & (ni < ci))
-        oval_ref[...] = jnp.where(better, nv, cv)
-        oidx_ref[...] = jnp.where(better, ni, ci)
+        @pl.when(j == 0)
+        def _():
+            oval_ref[...] = nv
+            oidx_ref[...] = ni
+
+        @pl.when(j > 0)
+        def _():
+            cv, ci = oval_ref[...], oidx_ref[...]
+            # strict argmax semantics: ties go to the smaller global id
+            better = (nv > cv) | ((nv == cv) & (ni < ci))
+            oval_ref[...] = jnp.where(better, nv, cv)
+            oidx_ref[...] = jnp.where(better, ni, ci)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("vocab", "bn", "interpret"))
+                   static_argnames=("vocab", "shard_width", "bn", "bk",
+                                    "interpret"))
 def cdc_fused_head_argmax_pallas(x: jax.Array, w_shards: jax.Array,
                                  parity_w: jax.Array, valid: jax.Array, *,
-                                 vocab: int, bn: int = 128,
+                                 vocab: int, shard_width: int | None = None,
+                                 bn: int = 512, bk: int = 512,
                                  interpret: bool = False
                                  ) -> tuple[jax.Array, jax.Array]:
     """Fused coded LM head + parity decode + greedy argmax.
@@ -148,34 +163,52 @@ def cdc_fused_head_argmax_pallas(x: jax.Array, w_shards: jax.Array,
     valid:    [T] bool shard validity; at most ONE False (Eq. 12 regime —
               the caller falls back to the reference MDS path beyond that).
     vocab:    logical vocabulary (merged columns >= vocab never win).
+    shard_width: logical m_l when the shards carry zero columns past it
+              (``pad_head_shards``); those columns never win.
+    bn/bk:    requested column and contraction tiles, rounded to legal
+              TPU tiles (``cdc_matmul.col_block`` / ``k_block``).
 
     Returns (token [b] int32, max_logit [b] f32) — argmax over the merged
     [b, T*m_l] logits, which are never materialised.
     """
-    t, k, m_l = w_shards.shape
+    t, k, cols = w_shards.shape
     b = x.shape[0]
-    bn = min(bn, m_l)
-    while m_l % bn:
-        bn //= 2
-    kernel = functools.partial(_fused_head_kernel, m_l=m_l, bn=bn,
-                               vocab=vocab)
+    bn, bk = col_block(cols, bn), k_block(k, bk)
+    kernel = functools.partial(_fused_head_kernel, m_l=shard_width or cols,
+                               bn=bn, vocab=vocab)
     val, idx = pl.pallas_call(
         kernel,
-        grid=(m_l // bn,),
+        grid=(pl.cdiv(cols, bn), k // bk),
         in_specs=[
-            pl.BlockSpec((t,), lambda j: (0,)),
-            pl.BlockSpec((b, k), lambda j: (0, 0)),
-            pl.BlockSpec((t, k, bn), lambda j: (0, 0, j)),
-            pl.BlockSpec((k, bn), lambda j: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((b, bk), lambda j, kk: (0, kk)),
+            pl.BlockSpec((t, bk, bn), lambda j, kk: (0, kk, j)),
+            pl.BlockSpec((bk, bn), lambda j, kk: (kk, j)),
         ],
         out_specs=[
-            pl.BlockSpec((b, 1), lambda j: (0, 0)),
-            pl.BlockSpec((b, 1), lambda j: (0, 0)),
+            pl.BlockSpec((b, 1), lambda j, kk: (0, 0)),
+            pl.BlockSpec((b, 1), lambda j, kk: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((t + 1, b, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(valid, x, w_shards, parity_w)
+    )(valid.astype(jnp.int32), x, w_shards, parity_w)
     return idx[:, 0], val[:, 0]
+
+
+def pad_head_shards(w_shards: jax.Array, parity_w: jax.Array):
+    """Zero-pad the head shards' columns to a multiple of 128 lanes, once,
+    outside the round: a lane-aligned [k, cols] operand keeps the TPU's
+    row-major tiled layout, where an unaligned one is laid out
+    column-major by XLA and copied back on every call. Pass the original
+    m_l as ``shard_width``."""
+    pad = -w_shards.shape[-1] % LANE
+    if not pad:
+        return w_shards, parity_w
+    return (jnp.pad(w_shards, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(parity_w, ((0, 0), (0, pad))))

@@ -31,11 +31,17 @@ bakes the 1/gen[e, d] back-substitution coefficient in. Beyond one
 erasure the callers (``kernels.ops``, ``executor.vstep``) fall back to
 the reference MDS path — never a silent wrong answer.
 
-Tile layout: grid (rows/bm, m_l/bn); per instance the FULL contraction
-dim k and the full (small) shard axis are resident, so the recovery math
-never leaves VMEM:
-  VMEM floats ~= bm*k + (T+r)*k*bn + (T+r)*bm*bn + bm*T*bn
-(k resident like the fused-head kernel; callers shrink bm/bn for large k).
+Tiling (what Mosaic lays out on a TPU): grid (rows/bm, m_l/bn, k/bk) with
+the contraction axis last and sequential. Column tiles are 128-lane
+multiples (``col_block``; the last tile may overhang m_l, and Pallas
+masks its stores) or, under 128 lanes, the whole shard width; the k axis streams (T+r) weight blocks of [bk, bn] into an
+f32 VMEM accumulator, so fast memory stays bounded for any k:
+  VMEM bytes ~= 2*(bm*bk + (T+r)*bk*bn)*itemsize      (double-buffered in)
+              + (T+r)*bm*bn*4                          (f32 accumulator)
+              + 2*bm*T*bn*itemsize                     (double-buffered out)
+The validity mask and the [r, T] generator live in SMEM as scalars; the
+per-column plan is a [1, bn] int32/f32 lane vector; each shard's [bm, bn]
+slab is stored straight into ``o_ref[:, t, :]``.
 """
 from __future__ import annotations
 
@@ -44,8 +50,49 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.coded_layer import folded_slot_map
+
+LANE = 128      # TPU vector lane width: the minor dim of every tile
+SUBLANE = 16    # rows per tile, safe for f32 (8) and bf16 (16)
+
+
+def col_block(m: int, bn: int) -> int:
+    """Lane-minor tile for a dim of width ``m``: a multiple of 128 lanes
+    no larger than ``bn`` or ``m`` that leaves the least overhang in the
+    last tile (ties go to the larger tile). A dim under 128 lanes is taken
+    whole. The grid is ``cdiv(m, tile)``: Pallas masks the stores of the
+    overhanging tile, and its columns never mix with others."""
+    if m < LANE:
+        return m
+    top = min(max(LANE, bn - bn % LANE), m - m % LANE)
+    return min(range(LANE, top + 1, LANE),
+               key=lambda b: (pl.cdiv(m, b) * b, -b))
+
+
+def k_block(k: int, bk: int) -> int:
+    """Contraction tile: the largest 128-multiple <= bk dividing k, or the
+    whole k when it is small or not lane-aligned."""
+    if k <= bk or k % LANE:
+        return k
+    return max(b for b in range(LANE, bk + 1, LANE) if k % b == 0)
+
+
+def row_block(rows: int, bm: int) -> int:
+    """Sublane tile: all rows when they fit one tile, else a multiple of
+    16 (legal for f32 and bf16); the last tile may overhang."""
+    bm = max(SUBLANE, bm - bm % SUBLANE)
+    return rows if rows <= bm else bm
+
+
+def mxu_dot(x: jax.Array, w: jax.Array) -> jax.Array:
+    """x @ w on the MXU in the weight's dtype with f32 accumulation; f32
+    weights get full f32 precision (TPU's default f32 dot is one bf16
+    pass)."""
+    prec = jax.lax.Precision.HIGHEST if w.dtype == jnp.float32 else None
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32,
+                   precision=prec)
 
 
 def eq12_plan(spec, valid: jax.Array, valid_parity: jax.Array,
@@ -79,60 +126,82 @@ def eq12_plan(spec, valid: jax.Array, valid_parity: jax.Array,
     return esel, coef
 
 
-def _decode_combine(y, p, gen, valid, esel, coef):
-    """Shared in-register tail: zero dead shards, Eq. 12-reconstruct the
-    missing one from its selected parity equation, emit merged layout.
+def _plan_operands(valid, gen, esel, coef):
+    """Kernel-side forms of the plan: the mask as int32 and the generator
+    as f32 scalars (SMEM), the per-column plan as [1, m_l] lane rows."""
+    return (valid.astype(jnp.int32), gen.astype(jnp.float32),
+            esel.astype(jnp.int32)[None, :],
+            coef.astype(jnp.float32)[None, :])
 
-    y: [T, bm, bn], p: [r, bm, bn] (f32); returns [bm, T, bn] f32."""
-    T = y.shape[0]
-    r = p.shape[0]
-    vmask = valid[:, None, None]
-    yz = jnp.where(vmask, y, 0.0)
-    # residual_j = p_j - sum_i gen[j, i] * y_i  (dead shards zeroed above)
-    residual = p - jnp.tensordot(gen, yz, axes=[[1], [0]])  # [r, bm, bn]
-    # per-column equation pick (esel) without NaN propagation from
-    # never-selected rows: where(), not a multiply-by-onehot
-    rows = jax.lax.broadcasted_iota(jnp.int32, (r, y.shape[2]), 0)
-    onehot = rows == esel[None, :]                          # [r, bn]
-    pick = jnp.sum(jnp.where(onehot[:, None, :], residual, 0.0), axis=0)
-    missing = pick * coef[None, :]                          # [bm, bn]
-    out = jnp.where(vmask, yz, missing[None])               # [T, bm, bn]
-    return jnp.moveaxis(out, 0, 1)                          # [bm, T, bn]
+
+def _eq12_store(o_ref, ys, ps, valid_ref, gen_ref, esel, coef):
+    """Shared in-register tail: zero dead shards, Eq. 12-reconstruct the
+    missing one from its selected parity equation, and store each shard's
+    [bm, bn] slab at ``o_ref[:, t, :]`` (merge order).
+
+    ys: T f32 [bm, bn] shard tiles; ps: r f32 [bm, bn] parity tiles;
+    valid_ref [T] int32 and gen_ref [r, T] f32 in SMEM; esel/coef [1, bn].
+    """
+    T, r = len(ys), len(ps)
+    alive = [valid_ref[t] != 0 for t in range(T)]
+    yz = [jnp.where(alive[t], ys[t], 0.0) for t in range(T)]
+    pick = jnp.zeros_like(yz[0])
+    for e in range(r):
+        # residual_e = p_e - sum_i gen[e, i] * y_i  (dead shards zeroed)
+        res = ps[e]
+        for t in range(T):
+            res = res - gen_ref[e, t] * yz[t]
+        # per-column equation pick without NaN propagation from rows that
+        # are never selected: where(), not a multiply-by-onehot
+        pick = jnp.where(esel == e, res, pick)
+    missing = pick * coef
+    for t in range(T):
+        o_ref[:, t, :] = jnp.where(alive[t], yz[t],
+                                   missing).astype(o_ref.dtype)
 
 
 # ------------------------------------------------- fused coded matmul ----
 
-def _coded_matmul_kernel(valid_ref, esel_ref, coef_ref, gen_ref, x_ref,
+def _coded_matmul_kernel(valid_ref, gen_ref, esel_ref, coef_ref, x_ref,
                          w_ref, pw_ref, *rest, fuse_norm: bool, eps: float):
     if fuse_norm:
-        gamma_ref, o_ref = rest
+        xs_ref, gamma_ref, o_ref, acc_ref = rest
     else:
-        (o_ref,) = rest
-    x = x_ref[...].astype(jnp.float32)                      # [bm, k]
+        o_ref, acc_ref = rest
+    T, r = w_ref.shape[0], pw_ref.shape[0]
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    x = x_ref[...].astype(jnp.float32)                      # [bm, bk]
     if fuse_norm:
-        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        xs = xs_ref[...].astype(jnp.float32)                # [bm, k]
+        var = jnp.mean(xs * xs, axis=-1, keepdims=True)
         x = x * jax.lax.rsqrt(var + eps) \
-            * gamma_ref[...].astype(jnp.float32)[None]
-    w = w_ref[...].astype(jnp.float32)                      # [T, k, bn]
-    pw = pw_ref[...].astype(jnp.float32)                    # [r, k, bn]
+            * gamma_ref[...].astype(jnp.float32)
     # the T shard GEMMs + the r parity GEMMs for this tile (MXU)
-    y = jnp.einsum("bk,tkn->tbn", x, w,
-                   preferred_element_type=jnp.float32)
-    p = jnp.einsum("bk,rkn->rbn", x, pw,
-                   preferred_element_type=jnp.float32)
-    out = _decode_combine(y, p, gen_ref[...].astype(jnp.float32),
-                          valid_ref[...], esel_ref[...], coef_ref[...])
-    o_ref[...] = out.astype(o_ref.dtype)
+    for t in range(T):
+        acc_ref[t] += mxu_dot(x, w_ref[t])
+    for e in range(r):
+        acc_ref[T + e] += mxu_dot(x, pw_ref[e])
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _():
+        _eq12_store(o_ref, [acc_ref[t] for t in range(T)],
+                    [acc_ref[T + e] for e in range(r)], valid_ref, gen_ref,
+                    esel_ref[...], coef_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "eps", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "eps",
+                                             "out_dtype", "interpret"))
 def cdc_coded_matmul_pallas(x: jax.Array, w_shards: jax.Array,
                             parity_w: jax.Array, gen: jax.Array,
                             esel: jax.Array, coef: jax.Array,
                             valid: jax.Array, *, gamma: jax.Array | None
                             = None, eps: float = 1e-5, bm: int = 128,
-                            bn: int = 128, out_dtype=None,
+                            bn: int = 256, bk: int = 512, out_dtype=None,
                             interpret: bool = False) -> jax.Array:
     """Fused (rmsnorm?) + coded shard GEMMs + Eq. 12 decode + merge.
 
@@ -142,6 +211,8 @@ def cdc_coded_matmul_pallas(x: jax.Array, w_shards: jax.Array,
               (callers unfold the slot-major folded layout first).
     gen:      [r, T] generator rows; esel/coef: the ``eq12_plan``.
     valid:    [T] bool; at most ONE False (callers fall back beyond).
+    bm/bn/bk: requested tiles, rounded to legal TPU tiles (``row_block``,
+              ``col_block``, ``k_block``).
 
     Returns merged [rows, T, m_l] — ``reshape(rows, T*m_l)`` IS the
     merged activation (merge order is written directly; no transpose,
@@ -152,55 +223,46 @@ def cdc_coded_matmul_pallas(x: jax.Array, w_shards: jax.Array,
     r = parity_w.shape[0]
     assert k == k2, (x.shape, w_shards.shape)
     out_dtype = out_dtype or x.dtype
-    bm, bn = min(bm, rows), min(bn, m_l)
-    rows_p = -(-rows // bm) * bm
-    m_l_p = -(-m_l // bn) * bn
-    if rows_p != rows:
-        x = jnp.pad(x, ((0, rows_p - rows), (0, 0)))
-    if m_l_p != m_l:
-        padn = ((0, 0), (0, 0), (0, m_l_p - m_l))
-        w_shards = jnp.pad(w_shards, padn)
-        parity_w = jnp.pad(parity_w, padn)
-        esel = jnp.pad(esel, (0, m_l_p - m_l))
-        coef = jnp.pad(coef, (0, m_l_p - m_l), constant_values=1.0)
+    bm, bn, bk = row_block(rows, bm), col_block(m_l, bn), k_block(k, bk)
     fuse_norm = gamma is not None
     kernel = functools.partial(_coded_matmul_kernel, fuse_norm=fuse_norm,
                                eps=eps)
     in_specs = [
-        pl.BlockSpec((t,), lambda i, j: (0,)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
-        pl.BlockSpec((r, t), lambda i, j: (0, 0)),
-        pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-        pl.BlockSpec((t, k, bn), lambda i, j: (0, 0, j)),
-        pl.BlockSpec((r, k, bn), lambda i, j: (0, 0, j)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),               # valid [T]
+        pl.BlockSpec(memory_space=pltpu.SMEM),               # gen [r, T]
+        pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),      # esel
+        pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),      # coef
+        pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+        pl.BlockSpec((t, bk, bn), lambda i, j, kk: (0, kk, j)),
+        pl.BlockSpec((r, bk, bn), lambda i, j, kk: (0, kk, j)),
     ]
-    args = [valid, esel, coef, gen, x, w_shards, parity_w]
+    args = [*_plan_operands(valid, gen, esel, coef), x, w_shards, parity_w]
     if fuse_norm:
-        in_specs.append(pl.BlockSpec((k,), lambda i, j: (0,)))
-        args.append(gamma)
-    out = pl.pallas_call(
+        # the whole row for the norm statistics, gamma tiled with k
+        in_specs += [pl.BlockSpec((bm, k), lambda i, j, kk: (i, 0)),
+                     pl.BlockSpec((1, bk), lambda i, j, kk: (0, kk))]
+        args += [x, gamma.reshape(1, k)]
+    return pl.pallas_call(
         kernel,
-        grid=(rows_p // bm, m_l_p // bn),
+        grid=(pl.cdiv(rows, bm), pl.cdiv(m_l, bn), k // bk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, t, bn), lambda i, j: (i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((rows_p, t, m_l_p), out_dtype),
+        out_specs=pl.BlockSpec((bm, t, bn), lambda i, j, kk: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((rows, t, m_l), out_dtype),
+        scratch_shapes=[pltpu.VMEM((t + r, bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
-    if rows_p != rows or m_l_p != m_l:
-        out = out[:rows, :, :m_l]
-    return out
 
 
 # --------------------------------------------------- decode-and-merge ----
 
-def _decode_merge_kernel(valid_ref, esel_ref, coef_ref, gen_ref, y_ref,
+def _decode_merge_kernel(valid_ref, gen_ref, esel_ref, coef_ref, y_ref,
                          p_ref, o_ref):
-    out = _decode_combine(y_ref[...].astype(jnp.float32),
-                          p_ref[...].astype(jnp.float32),
-                          gen_ref[...].astype(jnp.float32),
-                          valid_ref[...], esel_ref[...], coef_ref[...])
-    o_ref[...] = out.astype(o_ref.dtype)
+    T, r = y_ref.shape[0], p_ref.shape[0]
+    _eq12_store(o_ref, [y_ref[t].astype(jnp.float32) for t in range(T)],
+                [p_ref[e].astype(jnp.float32) for e in range(r)],
+                valid_ref, gen_ref, esel_ref[...], coef_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "out_dtype",
@@ -208,7 +270,7 @@ def _decode_merge_kernel(valid_ref, esel_ref, coef_ref, gen_ref, y_ref,
 def cdc_decode_merge_pallas(ys: jax.Array, parity: jax.Array,
                             gen: jax.Array, esel: jax.Array,
                             coef: jax.Array, valid: jax.Array, *,
-                            bm: int = 128, bn: int = 128, out_dtype=None,
+                            bm: int = 128, bn: int = 512, out_dtype=None,
                             interpret: bool = False) -> jax.Array:
     """Eq. 12 decode + merge of already-computed shard outputs.
 
@@ -221,30 +283,21 @@ def cdc_decode_merge_pallas(ys: jax.Array, parity: jax.Array,
     t, rows, m_l = ys.shape
     r = parity.shape[0]
     out_dtype = out_dtype or ys.dtype
-    bm, bn = min(bm, rows), min(bn, m_l)
-    rows_p = -(-rows // bm) * bm
-    m_l_p = -(-m_l // bn) * bn
-    if rows_p != rows or m_l_p != m_l:
-        pad = ((0, 0), (0, rows_p - rows), (0, m_l_p - m_l))
-        ys = jnp.pad(ys, pad)
-        parity = jnp.pad(parity, pad)
-        esel = jnp.pad(esel, (0, m_l_p - m_l))
-        coef = jnp.pad(coef, (0, m_l_p - m_l), constant_values=1.0)
-    out = pl.pallas_call(
+    bm, bn = row_block(rows, bm), col_block(m_l, bn)
+    return pl.pallas_call(
         _decode_merge_kernel,
-        grid=(rows_p // bm, m_l_p // bn),
+        grid=(pl.cdiv(rows, bm), pl.cdiv(m_l, bn)),
         in_specs=[
-            pl.BlockSpec((t,), lambda i, j: (0,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((r, t), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
             pl.BlockSpec((t, bm, bn), lambda i, j: (0, i, j)),
             pl.BlockSpec((r, bm, bn), lambda i, j: (0, i, j)),
         ],
         out_specs=pl.BlockSpec((bm, t, bn), lambda i, j: (i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((rows_p, t, m_l_p), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, t, m_l), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(valid, esel, coef, gen, ys, parity)
-    if rows_p != rows or m_l_p != m_l:
-        out = out[:rows, :, :m_l]
-    return out
+    )(*_plan_operands(valid, gen, esel, coef), ys, parity)

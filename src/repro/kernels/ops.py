@@ -1,6 +1,6 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively; on this CPU container they execute in
+On TPU the kernels compile natively; off the TPU they execute in
 ``interpret=True`` mode (the kernel body runs in Python on CPU) so every test
 and benchmark exercises the real kernel logic. ``use_pallas=False`` (or
 backends where even interpret is undesirable for perf) falls back to the
@@ -65,7 +65,8 @@ def _cost_cdc_encode(out, operands):
 
 
 def _cost_cdc_coded_matmul(out, operands):
-    # operand order: [valid, esel, coef, gen, x, w_shards, parity_w, gamma?]
+    # operand order: [valid, gen, esel, coef, x, w_shards, parity_w,
+    # (x, gamma)?]
     # out [rows, T, m_l]; T+r shard GEMMs of x [rows, k] @ [k, m_l]
     if not out or len(out[0][1]) != 3:
         return 0.0
@@ -167,7 +168,7 @@ def cdc_decode(y_shards, parity, valid, *, use_pallas=True, **block_kw):
 
 
 def fused_head_argmax(x, w_shards, parity_w, valid, *, vocab,
-                      use_pallas=True, **block_kw):
+                      shard_width=None, use_pallas=True, **block_kw):
     """Fused coded LM-head GEMM + Eq. 12 parity decode + greedy argmax.
 
     The batched executor's decode hot path: one kernel per round, the
@@ -185,10 +186,11 @@ def fused_head_argmax(x, w_shards, parity_w, valid, *, vocab,
             f"sum-parity regime), got {dead} dead; use the reference "
             f"decode path (full logits + MDS recovery) for this round")
     if not use_pallas:
-        return ref.fused_head_argmax_ref(x, w_shards, parity_w, valid, vocab)
+        return ref.fused_head_argmax_ref(x, w_shards, parity_w, valid, vocab,
+                                         shard_width)
     return cdc_fused_head_argmax_pallas(x, w_shards, parity_w, valid,
-                                        vocab=vocab, interpret=_interpret(),
-                                        **block_kw)
+                                        vocab=vocab, shard_width=shard_width,
+                                        interpret=_interpret(), **block_kw)
 
 
 def fused_coded_matmul(x, w, w_cdc, spec, valid, *, valid_parity=None,

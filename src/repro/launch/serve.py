@@ -36,6 +36,8 @@ whole chaos run replays bit-exact.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +48,7 @@ from repro.core.failure import StragglerModel
 from repro.faults import (AdaptiveRedundancyPlanner, InjectedLatency,
                           LatencySpec, PlannerConfig, attach_chaos,
                           attach_planner, measured_stall_hook, parse_chaos)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import TPCtx, build
 from repro.obs import (FlightRecorder, MetricsServer, validate_chrome_trace,
                        write_chrome_trace)
@@ -71,12 +74,15 @@ def _legacy(args, model, params):
               eng.straggler_latency(StragglerModel(), n_trials=5000))
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--coded", action="store_true")
     ap.add_argument("--tp", type=int, default=4)
+    ap.add_argument("--dtype", default="float32",
+                    choices=("float32", "bfloat16"),
+                    help="parameter and KV-cache dtype")
     ap.add_argument("--batch", type=int, default=2,
                     help="runtime: decode slots; legacy: batch size")
     ap.add_argument("--requests", type=int, default=6)
@@ -142,20 +148,42 @@ def main():
                     help="capture a jax.profiler trace of the run into DIR "
                          "(rounds annotated as decode_round steps; open "
                          "with TensorBoard or Perfetto)")
-    args = ap.parse_args()
+    return ap
 
+
+def build_model(args):
+    """(cfg, model, params) for ``args``: the registry config (cut to the
+    smoke config with ``--smoke``), random params from a fixed key."""
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
     ctx = TPCtx(tp=args.tp, mode="coded" if args.coded else "plain",
                 moe_capacity=0)
     model = build(cfg, ctx)
-    params = model.init(jax.random.PRNGKey(0))
-    if args.legacy or args.fail_step >= 0:
-        return _legacy(args, model, params)
+    # one compiled program: its f32 temporaries fuse into the outputs,
+    # where eager init would hold each layer stack's in f32 at once
+    params = jax.jit(model.init, static_argnums=1)(
+        jax.random.PRNGKey(0), jnp.dtype(args.dtype))
+    return cfg, model, params
 
+
+@dataclasses.dataclass
+class Serving:
+    """The serving stack ``build_serving`` wires for one run."""
+    stepper: ModelStepper
+    sched: ContinuousBatchingScheduler
+    injector: Any = None
+    tracer: FlightRecorder | None = None
+    server: MetricsServer | None = None
+
+
+def build_serving(args, model, params) -> Serving:
+    """Stepper, health controller (with the ``--fail-time-ms`` erasure),
+    continuous-batching scheduler over the slot-pool executor, and the
+    optional chaos, planner and metrics-server attachments."""
     stepper = ModelStepper(model, params,
-                           max_len=args.prompt_len + args.gen_tokens + 8)
+                           max_len=args.prompt_len + args.gen_tokens + 8,
+                           cache_dtype=jnp.dtype(args.dtype))
     events = [erasure(args.fail_time_ms, args.fail_shard)] \
         if args.fail_time_ms >= 0 else []
     health = ShardHealthController(stepper.n_shards, stepper.erasure_budget,
@@ -197,6 +225,12 @@ def main():
             stepper.n_shards, layout=model.ctx.code_layout,
             suitable=stepper.erasure_budget > 0 or not args.coded)
         attach_planner(sched, planner)
+    return Serving(stepper, sched, injector, tracer, server)
+
+
+def serve_requests(args, cfg, sched) -> list:
+    """Submit ``--requests`` random prompts (timed arrivals, or all at
+    once against ``--deadline-ms``) and run the scheduler until drained."""
     rng = np.random.default_rng(1)
 
     def extras():
@@ -225,24 +259,40 @@ def main():
     if args.profile:
         jax.profiler.stop_trace()
         print(f"profile: wrote jax.profiler trace to {args.profile}")
+    return completed
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    cfg, model, params = build_model(args)
+    if args.legacy or args.fail_step >= 0:
+        return _legacy(args, model, params)
+    srv = build_serving(args, model, params)
+    stepper, sched, injector, tracer, server = (
+        srv.stepper, srv.sched, srv.injector, srv.tracer, srv.server)
+    completed = serve_requests(args, cfg, sched)
     mode = "sequential" if sched.executor is None else \
-        ("batched+overlap" if rcfg.overlap else "batched")
+        ("batched+overlap" if sched.rcfg.overlap else "batched")
     print(f"completed {len(completed)}/{args.requests} requests "
           f"({mode}; shed {len(sched.shed)})")
     if completed:
         print("tokens (first request):", completed[0].tokens)
     if sched.executor is not None:
-        print(f"executor: {sched.executor.vstep.n_dispatches} round "
-              f"dispatches, {sched.executor.vstep.n_traces} trace(s)")
+        vstep = sched.executor.vstep
+        print(f"executor: {vstep.n_dispatches} round dispatches "
+              f"({vstep.n_fused} fused), {vstep.n_traces} trace(s)")
         if sched.executor.perf is not None \
                 and sched.executor.perf.n_observed:
             s = sched.executor.perf.summary()
+            share = (f"{s['roofline_utilization']:.4f} "
+                     f"({s['dominant']}-bound)"
+                     if "roofline_utilization" in s else "not measured")
             print(f"perf: {s['model_flops'] / 1e6:.2f} MFLOP useful/round "
                   f"({s['coded_overhead_frac']:.3f} coded overhead, "
                   f"{s['parity_device_equiv']:.3f} parity device-equiv), "
                   f"{s['achieved_flops_per_s'] / 1e9:.2f} GFLOP/s achieved, "
-                  f"{s['hbm_gbs']:.2f} GB/s, roofline utilization "
-                  f"{s['roofline_utilization']:.4f} ({s['dominant']}-bound)")
+                  f"{s['hbm_gbs']:.2f} GB/s, roofline utilization {share}")
     if injector is not None:
         c = sched.metrics.counters
         print(f"chaos: {c['faults_injected']} injected events, "

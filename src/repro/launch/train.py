@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch, smoke_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import TPCtx, build
 from repro.optim import AdamWConfig
 from repro.train import Trainer, TrainerConfig, TrainConfig
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
     ap.add_argument("--no-resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

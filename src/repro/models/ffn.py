@@ -191,14 +191,12 @@ def _moe_sharded(ctx: TPCtx, p: Params, cfg, xf, e: int, k: int, tp: int):
         # ONE combine: psum over the EP axis (the only wire cost)
         return jax.lax.psum(y, axis)
 
-    from repro.dist.compat import shard_map
-
     x_spec = P(batch_axes if batch_axes else None, None)
-    fn = shard_map(
-        f, mesh,
-        (x_spec, P(None, None), P(axis, None, None),
-         P(axis, None, None), P(axis, None, None)),
-        x_spec)
+    fn = jax.shard_map(
+        f, mesh=mesh,
+        in_specs=(x_spec, P(None, None), P(axis, None, None),
+                  P(axis, None, None), P(axis, None, None)),
+        out_specs=x_spec, check_vma=False)
     return fn(xf, p["router"]["w"], p["we1"], p["we3"], p["we2"])
 
 

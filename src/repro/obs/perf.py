@@ -28,8 +28,10 @@ the loop, per dispatch:
     with the MEASURED round wall time from ``pool.py`` into
     ``achieved_flops_per_s``, ``hbm_gbs`` and ``roofline_utilization``
     (= roofline-bound step time / measured time, so 1.0 means the round
-    runs exactly at the modelled hardware bound). Published three ways:
-    ``RuntimeMetrics.perf`` (-> Prometheus gauges), ``perf.counter``
+    runs exactly at the modelled hardware bound; reported only against
+    known peaks — the chip's row of ``roofline.analysis.PEAKS``, or the
+    ``hw`` a caller passes — never for a CPU by default). Published three
+    ways: ``RuntimeMetrics.perf`` (-> Prometheus gauges), ``perf.counter``
     events on the flight recorder's ``perf`` track (dual-stamped:
     deterministic args carry the static cost, wall-derived values ride in
     ``wall_args`` so traced chaos runs still replay bit-exact), and
@@ -48,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.tracer import NULL_RECORDER
-from repro.roofline.analysis import HW, roofline_terms
+from repro.roofline.analysis import device_peaks, roofline_terms
 from repro.roofline.hlo_cost import analyze_hlo
 
 
@@ -65,8 +67,9 @@ class RoundCost:
     parity_device_equiv: float   # parity / (useful / T): flat in T (Fig. 2)
     T: int
     r: int
-    bound_step_s: float          # roofline-bound round time on `hw`
-    dominant: str                # compute | memory | collective
+    bound_step_s: float | None   # roofline-bound round time on `hw`
+    dominant: str | None         # compute | memory | collective (None:
+    #                              no peaks for this device, e.g. a CPU)
     custom_calls_uncosted: float
 
     def as_dict(self) -> dict:
@@ -92,7 +95,7 @@ def _plain_round_flops(stepper, state, toks) -> float:
         last = logits[:, -1:]
         return new_state, jnp.argmax(last, axis=-1).astype(jnp.int32), last
 
-    return _analyze(jax.jit(_round), stepper._raw_params, state,
+    return _analyze(jax.jit(_round), stepper.params, state,
                     toks)["flops"]
 
 
@@ -100,8 +103,9 @@ def attribute_round_costs(vstep, state, toks, hw: dict | None = None
                           ) -> dict[str, RoundCost]:
     """Cost every compiled round variant of ``vstep`` over the given slot
     state. Returns {variant: RoundCost} — always ``reference``, plus
-    ``fused`` when the executor dispatches the full-Pallas round."""
-    hw = dict(hw or HW)
+    ``fused`` when the executor dispatches the full-Pallas round. The
+    roofline bound is computed only against ``hw`` peaks; without them it
+    stays None."""
     st = vstep.stepper
     coded = bool(st.coded)
     T = int(st.n_shards)
@@ -124,7 +128,7 @@ def attribute_round_costs(vstep, state, toks, hw: dict | None = None
         parity = max(flops - useful, 0.0)
         terms = roofline_terms(
             {"flops": flops, "bytes accessed": cost["bytes"]},
-            {"total": cost["wire_bytes"]}, hw)
+            {"total": cost["wire_bytes"]}, hw) if hw else {}
         out[variant] = RoundCost(
             variant=variant, flops=flops, bytes=float(cost["bytes"]),
             wire_bytes=float(cost["wire_bytes"]), useful_flops=float(useful),
@@ -132,11 +136,19 @@ def attribute_round_costs(vstep, state, toks, hw: dict | None = None
             coded_overhead_frac=parity / flops if flops else 0.0,
             parity_device_equiv=(parity / (useful / T)
                                  if coded and useful else 0.0),
-            T=T, r=r, bound_step_s=float(terms["bound_step_s"]),
-            dominant=str(terms["dominant"]),
+            T=T, r=r, bound_step_s=terms.get("bound_step_s"),
+            dominant=terms.get("dominant"),
             custom_calls_uncosted=float(
                 cost.get("custom_calls_uncosted", 0.0)))
     return out
+
+
+def _roofline_fields(cost: RoundCost) -> dict:
+    """The roofline bound and its limiting term, when peaks were known."""
+    if cost.bound_step_s is None:
+        return {}
+    return {"bound_step_us": cost.bound_step_s * 1e6,
+            "dominant": cost.dominant}
 
 
 class PerfMonitor:
@@ -152,7 +164,9 @@ class PerfMonitor:
     def __init__(self, metrics=None, tracer=None, hw: dict | None = None):
         self.metrics = metrics
         self.tracer = tracer if tracer is not None else NULL_RECORDER
-        self.hw = dict(hw or HW)
+        # the chip's own peaks; off the TPU no roofline share is reported
+        # unless the caller names the hardware to hold the round against
+        self.hw = hw if hw is not None else device_peaks()
         self.costs: dict[str, RoundCost] = {}
         self.n_observed = 0
         self.last_variant: str | None = None
@@ -177,8 +191,7 @@ class PerfMonitor:
                     parity_flops=cost.parity_flops,
                     coded_overhead_frac=cost.coded_overhead_frac,
                     parity_device_equiv=cost.parity_device_equiv,
-                    T=cost.T, r=cost.r, dominant=cost.dominant,
-                    bound_step_us=cost.bound_step_s * 1e6)
+                    T=cost.T, r=cost.r, **_roofline_fields(cost))
         if self.metrics is not None:
             self.metrics.set_perf(self._static_summary())
         return self.costs
@@ -221,18 +234,21 @@ class PerfMonitor:
                     "achieved_gflops_per_s":
                         derived["achieved_flops_per_s"] / 1e9,
                     "hbm_gbs": derived["hbm_gbs"],
-                    "roofline_utilization":
-                        derived["roofline_utilization"]})
+                    **({"roofline_utilization":
+                        derived["roofline_utilization"]}
+                       if "roofline_utilization" in derived else {})})
 
     def derived(self, cost: RoundCost, round_ms: float) -> dict:
         """Achieved rates for one measured round period."""
         s = round_ms / 1e3
-        return {
+        out = {
             "achieved_flops_per_s": cost.flops / s,
             "hbm_gbs": cost.bytes / s / 1e9,
-            "roofline_utilization": cost.bound_step_s / s,
             "round_ms": float(round_ms),
         }
+        if cost.bound_step_s is not None:
+            out["roofline_utilization"] = cost.bound_step_s / s
+        return out
 
     # ------------------------------------------------------------ reading ----
     def _headline(self) -> RoundCost | None:
@@ -254,8 +270,7 @@ class PerfMonitor:
             "parity_flops": cost.parity_flops,
             "coded_overhead_frac": cost.coded_overhead_frac,
             "parity_device_equiv": cost.parity_device_equiv,
-            "bound_step_us": cost.bound_step_s * 1e6,
-            "dominant": cost.dominant,
+            **_roofline_fields(cost),
             "T": cost.T, "r": cost.r,
             "custom_calls_uncosted": cost.custom_calls_uncosted,
         }

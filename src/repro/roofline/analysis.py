@@ -16,7 +16,7 @@ ring-model bytes:
   reduce-scatter: in_bytes * (k-1)/k  = out_bytes * (k-1)
   all-to-all:    bytes * (k-1)/k
   collective-permute: bytes
-Hardware: TPU v5e-like — 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link ICI.
+Hardware: one row of ``PEAKS`` per ``jax.Device.device_kind``.
 """
 from __future__ import annotations
 
@@ -25,11 +25,36 @@ import re
 
 import numpy as np
 
-HW = {
-    "peak_flops": 197e12,   # bf16
-    "hbm_bw": 819e9,        # bytes/s
-    "link_bw": 50e9,        # bytes/s per ICI link
+#: Published per-chip peaks, keyed by ``device_kind``. TPU v5e ("TPU v5
+#: lite"), from Google Cloud's "TPU v5e" documentation page: 197 TFLOP/s
+#: bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect
+#: over 4 ICI links (50 GB/s each).
+PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,   # bf16
+        "hbm_bw": 819e9,        # bytes/s
+        "link_bw": 50e9,        # bytes/s per ICI link
+    },
 }
+
+#: The design target of the compile-only dry-run (a v5e fleet).
+HW = PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device=None) -> dict[str, float] | None:
+    """Peaks of ``device`` (default: the first JAX device). None off the
+    TPU — a CPU has no roofline share here; a TPU kind missing from
+    ``PEAKS`` raises rather than borrow another chip's numbers."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for TPU kind "
+                       f"{device.device_kind!r}; add its row to "
+                       f"repro.roofline.analysis.PEAKS")
+    return PEAKS[device.device_kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

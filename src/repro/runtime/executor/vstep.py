@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.cdc_decode import pad_head_shards
 
 
 def _fused_supported(stepper) -> bool:
@@ -65,6 +66,7 @@ class VStep:
         self.use_fused = bool(use_fused) and _fused_supported(stepper)
         self.n_traces = 0
         self.n_dispatches = 0
+        self.n_fused = 0        # of n_dispatches, full-Pallas rounds
         # which compiled program the LAST round() call dispatched — the
         # perf monitor attributes each harvested round to its variant
         self.last_variant = "reference"
@@ -93,10 +95,12 @@ class VStep:
                 model, ctx=dataclasses.replace(model.ctx, fused_body=True))
             hidden, new_state = fm.decode(params, state, toks, valid,
                                           return_hidden=True)
-            tok, _ = ops.fused_head_argmax(
+            tok, val = ops.fused_head_argmax(
                 hidden[:, -1, :].astype(jnp.float32), w_shards, parity_w,
-                valid, vocab=stepper.model.cfg.vocab)
-            return new_state, tok[:, None]
+                valid, vocab=stepper.model.cfg.vocab,
+                shard_width=params["lm_head"]["w"].shape[1]
+                // stepper.n_shards)
+            return new_state, tok[:, None], val
 
         self._round_fused = jax.jit(_round_fused)
         self._head_cache: tuple[int, Any, Any] | None = None
@@ -104,14 +108,18 @@ class VStep:
     # ----------------------------------------------------------- fused ----
     def _head_shards(self):
         """[T, k, m_l] column shards + sum-parity weight of the LM head,
-        cached per params object (refreshed by re-encode)."""
+        zero-padded to whole lanes and cached per params object
+        (refreshed by re-encode)."""
         params = self.stepper.params
         if self._head_cache is None or self._head_cache[0] != id(params):
             w = params["lm_head"]["w"]
             k, m = w.shape
             t = self.stepper.n_shards
             w_shards = jnp.moveaxis(w.reshape(k, t, m // t), 1, 0)
-            self._head_cache = (id(params), w_shards, w_shards.sum(0))
+            # the sum parity in f32, like every parity weight (CodeSpec)
+            parity_w = w_shards.astype(jnp.float32).sum(0)
+            self._head_cache = (id(params),
+                                *pad_head_shards(w_shards, parity_w))
         return self._head_cache[1], self._head_cache[2]
 
     # ----------------------------------------------------------- rounds ----
@@ -126,9 +134,10 @@ class VStep:
         if self.use_fused and v is not None \
                 and int(st.n_shards - np.asarray(valid).sum()) <= 1:
             self.last_variant = "fused"
+            self.n_fused += 1
             w_shards, parity_w = self._head_shards()
-            new_state, nxt = self._round_fused(st.params, state, toks, v,
-                                               w_shards, parity_w)
+            new_state, nxt, _ = self._round_fused(st.params, state, toks, v,
+                                                  w_shards, parity_w)
             return new_state, nxt, None
         self.last_variant = "reference"
         return self._round(st.params, state, toks, v)

@@ -60,7 +60,9 @@ class ModelStepper:
         # flight recorder (repro.obs); the scheduler re-binds its own so
         # code-geometry changes land in the same event stream
         self.tracer = tracer if tracer is not None else NULL_RECORDER
-        self._raw_params = params
+        # parity is always recomputed from the "w" leaves, so re-encodes
+        # start from self.params: keeping the caller's tree as well would
+        # hold a second copy of every parity weight on the device
         self.params = model.encode_offline(params)
         self.coded = bool(model.ctx.coded)
         self.n_shards = max(int(model.ctx.tp), 1)
@@ -82,7 +84,7 @@ class ModelStepper:
         """Offline parity re-encode (paper §5.1): run after a healed shard
         rejoins or a standby replica is swapped in."""
         t0 = time.perf_counter()
-        self.params = self.model.encode_offline(self._raw_params)
+        self.params = self.model.encode_offline(self.params)
         self.last_reencode_wall_ms = (time.perf_counter() - t0) * 1e3
 
     def set_code_r(self, code_r: int) -> bool:
@@ -100,7 +102,7 @@ class ModelStepper:
         r_old = int(self.model.ctx.code_r)
         ctx = dataclasses.replace(self.model.ctx, code_r=code_r)
         self.model = dataclasses.replace(self.model, ctx=ctx)
-        self.params = self.model.encode_offline(self._raw_params)
+        self.params = self.model.encode_offline(self.params)
         spec = ctx.spec
         self.erasure_budget = int(spec.max_device_failures) if spec else 0
         if self.tracer.enabled:

@@ -31,13 +31,14 @@ def _run(code: str) -> str:
 def test_sharded_coded_forward_matches_single_device():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_arch, smoke_config
         from repro.models import TPCtx, build
         from repro.dist.sharding import param_shardings, batch_spec
 
         assert len(jax.devices()) == 8
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = smoke_config(get_arch("granite-3-8b"))
 
         # single-device reference (same logical T=4 coded math)
@@ -73,12 +74,13 @@ def test_sharded_coded_forward_matches_single_device():
 def test_plain_tp_sharded_matches_single_device():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding
         from repro.configs import get_arch, smoke_config
         from repro.models import TPCtx, build
         from repro.dist.sharding import param_shardings, batch_spec
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = smoke_config(get_arch("qwen2-moe-a2.7b"))
         ctx0 = TPCtx(tp=4, moe_capacity=0)
         m0 = build(cfg, ctx0)
@@ -104,12 +106,13 @@ def test_multipod_mesh_and_elastic_restore():
     the 8-device mesh restores onto a 1-device process (elastic re-mesh)."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_arch, smoke_config
         from repro.models import TPCtx, build
         from repro.dist.sharding import param_shardings
         from repro.ckpt import save
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = smoke_config(get_arch("h2o-danube-1.8b"))
         ctx = TPCtx(tp=2, mesh=mesh)
         m = build(cfg, ctx)
@@ -148,13 +151,14 @@ def test_shardmap_coded_matmul_explicit_placement():
     erasure onto the real mesh devices holding that shard."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import CodedDenseSpec, CodeSpec, coded_matmul, \\
             make_parity_weights
         from repro.dist.collectives import coded_matmul_shardmap
         from repro.runtime.health import ShardHealthController, erasure, \\
             recovery
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         T = 4
         spec = CodedDenseSpec(CodeSpec(T, 2))
         kx, kw = jax.random.split(jax.random.PRNGKey(0))

@@ -77,10 +77,11 @@ def test_shardmap_triple_equivalence_properties():
         from repro.core import CodedDenseSpec, CodeSpec, coded_matmul, \\
             make_parity_weights
         from repro.dist.collectives import coded_matmul_shardmap
+        from repro.launch.mesh import make_mesh
 
         assert len(jax.devices()) == 8
-        MESHES = {{2: jax.make_mesh((4, 2), ("data", "model")),
-                   4: jax.make_mesh((2, 4), ("data", "model"))}}
+        MESHES = {{2: make_mesh((4, 2), ("data", "model")),
+                   4: make_mesh((2, 4), ("data", "model"))}}
 
         @settings(max_examples=10, deadline=None)
         @given(b=st.integers(1, 5), k=st.integers(1, 40),

@@ -20,6 +20,7 @@ import pytest
 
 from repro.configs import get_arch, smoke_config
 from repro.kernels import ops, ref
+from repro.kernels.cdc_decode import pad_head_shards
 from repro.models import TPCtx, build
 from repro.runtime import (AdmissionQueue, ContinuousBatchingScheduler,
                            Request, RequestState, RuntimeConfig,
@@ -192,6 +193,33 @@ def test_fused_head_matches_reference_grid(t, r):
                                    rtol=1e-5)
 
 
+@pytest.mark.parametrize("m_l", [40, 200])
+def test_fused_head_lane_padded_shards(m_l):
+    """Head shards zero-padded to whole 128-lane tiles (what the executor
+    caches) give the same token and max logit as the unpadded shards,
+    and the padding columns never win, even when every logit is
+    negative."""
+    t, b, k = 4, 3, 32
+    rng = np.random.default_rng(m_l)
+    x = jnp.asarray(rng.normal(size=(b, k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(t, k, m_l)) - 3.0, jnp.float32)
+    vocab = t * m_l - 3
+    wp, pwp = pad_head_shards(w, w.sum(0))
+    assert wp.shape[-1] % 128 == 0 and pwp.shape[-1] == wp.shape[-1]
+    for valid in (jnp.ones(t, bool), jnp.asarray([True, False, True, True])):
+        tok, val = ops.fused_head_argmax(x, w, w.sum(0), valid, vocab=vocab)
+        ptok, pval = ops.fused_head_argmax(x, wp, pwp, valid, vocab=vocab,
+                                           shard_width=m_l)
+        rtok, rval = ref.fused_head_argmax_ref(x, wp, pwp, valid, vocab,
+                                               shard_width=m_l)
+        np.testing.assert_array_equal(np.asarray(ptok), np.asarray(tok))
+        np.testing.assert_array_equal(np.asarray(rtok), np.asarray(tok))
+        np.testing.assert_allclose(np.asarray(pval), np.asarray(val),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(rval), np.asarray(val),
+                                   rtol=1e-5)
+
+
 def test_fused_round_matches_reference_round(coded):
     """End-to-end: the fused-head batched round produces the same next
     tokens as the reference (full-logits) round, fault-free and with one
@@ -237,12 +265,12 @@ def test_serving_engine_delegates_to_executor(coded):
     the batched path it now delegates to."""
     cfg, stepper = coded
     model = stepper.model
-    eng = ServingEngine(model, stepper._raw_params,
+    eng = ServingEngine(model, stepper.params,
                         ServeConfig(max_len=48, batch=2,
                                     cache_dtype=jnp.float32))
     batch = model.dummy_batch(jax.random.PRNGKey(1), 2, 8)
     got = eng.generate(batch, 6, fail_at={2: 1})
-    eng2 = ServingEngine(model, stepper._raw_params,
+    eng2 = ServingEngine(model, stepper.params,
                          ServeConfig(max_len=48, batch=2,
                                      cache_dtype=jnp.float32))
     eng2.inject_failure(1)  # pre-kill so the sequential run sees the same
@@ -250,7 +278,7 @@ def test_serving_engine_delegates_to_executor(coded):
     want_pre = eng2._generate_sequential(batch, 6, fail_at=None)
     # tokens after the injection step must match the always-degraded run;
     # before it, the healthy run (coded recovery is exact either way)
-    healthy = ServingEngine(model, stepper._raw_params,
+    healthy = ServingEngine(model, stepper.params,
                             ServeConfig(max_len=48, batch=2,
                                         cache_dtype=jnp.float32))
     want_ok = healthy._generate_sequential(batch, 6, fail_at=None)

@@ -17,6 +17,7 @@ round-trip HBM.
 import itertools
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,23 +31,11 @@ from repro.core.coded_layer import (CodedDenseSpec, coded_matmul,
                                     decode_and_merge, make_parity_weights)
 from repro.core.coding import CodeSpec
 from repro.kernels import ops, ref
+from repro.kernels.ref import ORACLE_TOL, TOL
 from repro.models.common import rmsnorm
 
-# ---------------------------------------------------------------------------
-# Tolerance contract. The kernel accumulates every GEMM in f32; the
-# reference path accumulates in the input dtype (bf16 stays bf16), so the
-# fused-vs-reference delta is bounded by the REFERENCE's accumulation
-# error, not the kernel's. The oracle mirrors the kernel's f32 math
-# exactly and is bit-identical in interpret mode; the looser oracle bound
-# only allows for native-TPU rounding.
-TOL = {
-    "float32": dict(rtol=1e-4, atol=1e-4),    # vs reference / plain
-    "bfloat16": dict(rtol=6e-2, atol=6e-2),
-}
-ORACLE_TOL = {
-    "float32": dict(rtol=1e-5, atol=1e-5),    # vs ref.py oracle
-    "bfloat16": dict(rtol=2e-2, atol=2e-2),
-}
+# Tolerance contract (TOL vs reference/plain, ORACLE_TOL vs the ref.py
+# oracle): defined with the oracles in ``repro.kernels.ref``.
 
 CASES = [(T, r, layout)
          for T in (2, 4) for r in (1, 2)
@@ -264,9 +253,9 @@ def _count_primitives(closed_jaxpr):
                 continue                      # kernel-internal math
             for v in eqn.params.values():
                 for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                    if isinstance(sub, jex_core.ClosedJaxpr):
                         walk(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
+                    elif isinstance(sub, jex_core.Jaxpr):
                         walk(sub)
 
     walk(closed_jaxpr.jaxpr)

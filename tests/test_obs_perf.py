@@ -1,8 +1,9 @@
 """Perf observability (``repro.obs.perf`` + ``repro.obs.history``).
 
-Tier-1 properties: roofline attribution of the live executor rounds gives
-a CPU-smoke ``roofline_utilization`` in (0, 1] (CPU is far slower than
-the TPU-modelled bound), the paper's Fig. 2 constant-cost claim holds as
+Tier-1 properties: roofline attribution of the live executor rounds,
+held against the v5e peaks the test names, gives a CPU-smoke
+``roofline_utilization`` in (0, 1] (CPU is far slower than the TPU
+bound) while an unnamed CPU reports no roofline share at all, the paper's Fig. 2 constant-cost claim holds as
 a runtime metric (``parity_device_equiv`` flat across T at fixed r while
 ``coded_overhead_frac`` falls), the fused full-Pallas round reports
 non-zero FLOPs within 5% of the reference round at r=1 (the Pallas
@@ -29,6 +30,7 @@ from repro.obs import (FlightRecorder, MetricsServer, chrome_trace,
 from repro.obs.history import (append_snapshot, check_history, compare,
                                load_history, make_snapshot)
 from repro.obs.perf import PerfMonitor, attribute_round_costs
+from repro.roofline.analysis import PEAKS, device_peaks
 from repro.roofline.hlo_cost import analyze_hlo
 from repro.runtime import (ContinuousBatchingScheduler, RuntimeConfig,
                            run_arrivals)
@@ -37,6 +39,7 @@ from repro.serve import ModelStepper
 
 GEN = 4
 PROMPT_LEN = 8
+V5E = PEAKS["TPU v5 lite"]
 
 
 def _stepper(tp=4, code_r=1, arch="granite-3-8b"):
@@ -45,6 +48,15 @@ def _stepper(tp=4, code_r=1, arch="granite-3-8b"):
                              moe_capacity=0))
     params = model.init(jax.random.PRNGKey(0))
     return cfg, ModelStepper(model, params, max_len=32)
+
+
+def _perf_scheduler(stepper, tracer=None, hw=V5E):
+    """A perf-accounting scheduler whose monitor holds rounds against
+    ``hw`` (the CPU has no peaks of its own)."""
+    sched = ContinuousBatchingScheduler(
+        stepper, RuntimeConfig(n_slots=2, perf=True), tracer=tracer)
+    sched.executor.perf.hw = hw
+    return sched
 
 
 def _workload(cfg, n=3, span_ms=150.0):
@@ -67,8 +79,7 @@ def perf_run():
     """One CPU smoke serve with perf accounting + tracing on."""
     cfg, stepper = _stepper()
     tracer = FlightRecorder()
-    sched = ContinuousBatchingScheduler(
-        stepper, RuntimeConfig(n_slots=2, perf=True), tracer=tracer)
+    sched = _perf_scheduler(stepper, tracer)
     run_arrivals(sched, _workload(cfg))
     return sched, tracer
 
@@ -89,6 +100,35 @@ def test_utilization_in_unit_interval_on_cpu(perf_run):
     assert sched.metrics.perf["roofline_utilization"] == \
         s["roofline_utilization"]
     assert sched.metrics.perf["n_rounds_observed"] == perf.n_observed
+
+
+def test_no_roofline_share_without_peaks():
+    """Off the TPU the monitor has no peaks of its own: the CPU run still
+    reports counts and achieved rates, but no roofline share or bound."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the chip has peaks of its own")
+    assert device_peaks() is None
+    cfg, stepper = _stepper()
+    sched = _perf_scheduler(stepper, hw=None)
+    run_arrivals(sched, _workload(cfg, n=2))
+    s = sched.executor.perf.summary()
+    assert sched.executor.perf.n_observed > 0 and s["model_flops"] > 0
+    for key in ("roofline_utilization", "bound_step_us", "dominant"):
+        assert key not in s and key not in sched.metrics.perf
+
+
+def test_unknown_tpu_kind_has_no_peaks():
+    """A TPU kind missing from the table is an error, not a default."""
+    class Dev:
+        platform, device_kind = "tpu", "TPU v99"
+
+    with pytest.raises(KeyError, match="TPU v99"):
+        device_peaks(Dev())
+
+    class V5e:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    assert device_peaks(V5e()) is V5E
 
 
 def test_parity_device_equiv_flat_across_T():
@@ -249,8 +289,7 @@ def test_perf_without_tracer_emits_nothing():
     """Perf accounting with tracing disabled: gauges still update, but the
     NULL recorder records zero events (and its emit is a no-op branch)."""
     cfg, stepper = _stepper()
-    sched = ContinuousBatchingScheduler(
-        stepper, RuntimeConfig(n_slots=2, perf=True))
+    sched = _perf_scheduler(stepper)
     run_arrivals(sched, _workload(cfg, n=2))
     assert sched.executor.perf.n_observed > 0
     assert sched.metrics.perf["roofline_utilization"] > 0

@@ -25,9 +25,10 @@ def _run(code: str) -> str:
 def test_gpipe_over_pod_matches_sequential():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.dist.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+        mesh = make_mesh((4, 2), ("pod", "data"))
         L, D = 8, 32
         keys = jax.random.split(jax.random.PRNGKey(0), L)
         params = {"w": jnp.stack([
